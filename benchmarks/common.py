@@ -5,6 +5,10 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.launch.compile_cache import use_compile_cache  # noqa: E402
+
+use_compile_cache()
+
 ROWS = []
 
 

@@ -190,6 +190,13 @@ def lambda_line(fn: Callable) -> int:
         return 0
 
 
+def _index_map(spec: pl.BlockSpec) -> Callable:
+    """The index-map callable as written in the kernel wrapper: BlockSpec
+    wraps it (``functools.update_wrapper``), and the verifier reads the
+    lambda's source and closure, so unwrap to the original."""
+    return inspect.unwrap(spec.index_map)
+
+
 def _flat_specs(specs) -> List[pl.BlockSpec]:
     return list(jax.tree_util.tree_leaves(
         specs, is_leaf=lambda x: isinstance(x, pl.BlockSpec)))
@@ -224,20 +231,22 @@ def capture(into: List[KernelModel], *, name: str = "", case: str = ""):
             tensors = operands[nsp:]
             in_models = []
             for spec, op in zip(ins, tensors):
+                imap = _index_map(spec)
                 in_models.append(SpecModel(
                     block_shape=tuple(int(d) for d in spec.block_shape),
-                    index_map=spec.index_map,
+                    index_map=imap,
                     shape=tuple(op.shape),
                     dtype=np.dtype(op.dtype),
-                    name="", line=lambda_line(spec.index_map)))
+                    name="", line=lambda_line(imap)))
             out_models = []
             for spec, st in zip(outs, out_structs):
+                imap = _index_map(spec)
                 out_models.append(SpecModel(
                     block_shape=tuple(int(d) for d in spec.block_shape),
-                    index_map=spec.index_map,
+                    index_map=imap,
                     shape=tuple(st.shape),
                     dtype=np.dtype(st.dtype),
-                    name="", line=lambda_line(spec.index_map)))
+                    name="", line=lambda_line(imap)))
             params = _positional_params(kfn)
             model = KernelModel(
                 name=name or kfn.__name__.lstrip("_"),

@@ -24,10 +24,8 @@ class RetraceError(SanitizerError):
 
 
 def _cache_size(fn) -> Optional[int]:
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return None
+    size = getattr(fn, "_cache_size", None)
+    return None if size is None else int(size())
 
 
 class RetraceSan:

@@ -242,8 +242,12 @@ class NumericsBackend:
         self.allocator = allocator
         self.bt_width = cache_slots // page_size if self.paged else 0
         if params is None:
-            params, _ = split(model_lib.init_params(
-                cfg, jax.random.PRNGKey(seed)))
+            # one jitted init: each weight's random draw fuses into its
+            # output, so a published-width model never holds a weight's
+            # random bits and intermediates beside the weights already made
+            params = jax.jit(
+                lambda k: split(model_lib.init_params(cfg, k))[0],
+                static_argnums=())(jax.random.PRNGKey(seed))
         self.params = params
         row_cache = model_lib.cache_abstract(cfg, 1, cache_slots)
         self.cache = cache_lib.zeros_paged(
@@ -257,16 +261,14 @@ class NumericsBackend:
                                    bt_width=self.bt_width)
         self.staging = StagingCache(staging_slots,
                                     on_upload=self._count_upload)
-        # donation: real on accelerators; skipped on CPU (unsupported there)
-        self._donate = jax.default_backend() != "cpu"
         mask_ok = model_lib.supports_write_mask(cfg)
         self._decode_legacy_jit = jax.jit(
             functools.partial(self._decode_legacy_fn, cfg, self._mode_str()),
-            donate_argnums=(1,) if self._donate else ())
+            donate_argnums=(1,))
         self._decode_jit = jax.jit(
             functools.partial(self._decode_fused_fn, cfg, self._mode_str(),
                               temperature, mask_ok),
-            donate_argnums=(1, 2, 3, 7) if self._donate else ())
+            donate_argnums=(1, 2, 3, 7))
         self._megastep_jits = {}
         self._prefill_jit = {}
         self._chunk_jit = {}
@@ -456,7 +458,7 @@ class NumericsBackend:
             clear_ids[:len(claimed)] = claimed
             key = (Nb, Lp, C)
             if key not in self._prefill_jit:
-                donate = (5, 6, 7, 8, 9) if self._donate else ()
+                donate = (5, 6, 7, 8, 9)
                 self._prefill_jit[key] = jax.jit(functools.partial(
                     self._prefill_paged_fn, self.cfg, self._mode_str(),
                     Sp, self.temperature), donate_argnums=donate)
@@ -469,7 +471,7 @@ class NumericsBackend:
         else:
             key = (Nb, Lp)
             if key not in self._prefill_jit:
-                donate = (5, 6, 7, 8, 9) if self._donate else ()
+                donate = (5, 6, 7, 8, 9)
                 self._prefill_jit[key] = jax.jit(functools.partial(
                     self._prefill_fn, self.cfg, self._mode_str(),
                     self.cache_slots, self.temperature,
@@ -591,13 +593,13 @@ class NumericsBackend:
         key = (Cb, bool(final))
         if key not in self._chunk_jit:
             if final:
-                donate = (7, 8, 9, 10, 11) if self._donate else ()
+                donate = (7, 8, 9, 10, 11)
                 self._chunk_jit[key] = jax.jit(functools.partial(
                     self._prefill_chunk_final_fn, self.cfg,
                     self._mode_str(), self.temperature),
                     donate_argnums=donate)
             else:
-                donate = (4,) if self._donate else ()
+                donate = (4,)
                 self._chunk_jit[key] = jax.jit(functools.partial(
                     self._prefill_chunk_fn, self.cfg, self._mode_str()),
                     donate_argnums=donate)
@@ -724,7 +726,7 @@ class NumericsBackend:
                             "kv:", "megastep block table")
         pipe.refresh(ready, row_slot, row_pages)
         if K not in self._megastep_jits:
-            donate = (1, 2, 3, 7) if self._donate else ()
+            donate = (1, 2, 3, 7)
             self._megastep_jits[K] = jax.jit(functools.partial(
                 self._megastep_fn, self.cfg, self._mode_str(),
                 self.temperature, model_lib.supports_write_mask(self.cfg),

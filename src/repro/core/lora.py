@@ -11,6 +11,7 @@ paper sec 2.3/ sec 5).
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -54,6 +55,13 @@ def lora_target_dims(cfg: ModelConfig, target: str) -> Tuple[int, int]:
     raise ValueError(target)
 
 
+def adapter_seed(spec: AdapterSpec) -> int:
+    """Seed of an adapter's synthesized weights: a stable digest of
+    (uid, seed), so every process derives the same weights (Python's
+    str hash is salted per process)."""
+    return zlib.crc32(f"{spec.uid}:{spec.seed}".encode())
+
+
 def make_adapter_weights(cfg: ModelConfig, spec: AdapterSpec,
                          dtype=None) -> Dict[str, Dict[str, np.ndarray]]:
     """Synthesize adapter weights (paper uses dummy weights, sec 7.1 footnote;
@@ -62,7 +70,7 @@ def make_adapter_weights(cfg: ModelConfig, spec: AdapterSpec,
     dtype = dtype or cfg.jdtype
     r_max = cfg.lora.max_rank
     L = cfg.n_layers + cfg.n_enc_layers
-    rng = np.random.default_rng(abs(hash((spec.uid, spec.seed))) % (2 ** 31))
+    rng = np.random.default_rng(adapter_seed(spec))
     r = min(spec.rank, r_max)      # pool is sized for max_rank
     out = {}
     for tgt in cfg.lora.targets:

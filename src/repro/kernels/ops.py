@@ -1,6 +1,6 @@
-"""Jit'd public wrappers for the Pallas kernels, with automatic fallback to
-the pure-jnp oracle where Pallas cannot lower (CPU backend uses
-interpret=True; the oracle itself is exported for the dry-run path)."""
+"""Jit'd public wrappers for the Pallas kernels (each runs in interpret mode
+on the CPU backend and lowers to Mosaic on TPU; the pure-jnp oracle is
+exported for the dry-run path)."""
 from __future__ import annotations
 
 import functools

@@ -22,7 +22,6 @@ import traceback     # noqa: E402
 import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from repro import jax_compat  # noqa: E402
 from repro import roofline, sharding as shd                     # noqa: E402
 from repro.configs.base import (INPUT_SHAPES, ModelConfig,      # noqa: E402
                                 all_arch_ids, combo_is_supported, get_config)
@@ -217,7 +216,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool = False,
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
     t0 = time.time()
-    with jax_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             fn, args, in_sh = build_train(cfg, shape, mesh)
             donate = ()
@@ -250,7 +249,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool = False,
     bytes_hbm = float(cost.get("bytes accessed", 0.0))
     coll_total = float(sum(coll.values()))
     if probes:
-        with jax_compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             pc = _probe_costs(cfg, shape, mesh)
         flops, bytes_hbm, coll_total = pc["flops"], pc["bytes"], pc["coll"]
         rec["probe_per_layer"] = pc["per_layer"]

@@ -12,7 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.models.layers import emb_w
 from repro.models.param import Box, dense_init
 
@@ -117,7 +116,7 @@ def moe_apply(cfg, p, x, *, group_by_sequence=True):
         U = jax.sharding.PartitionSpec.UNCONSTRAINED
 
         def _c(t):
-            if "model" not in jax_compat.current_axis_names():
+            if "model" not in jax.sharding.get_abstract_mesh().axis_names:
                 return t          # single-device (tests): no-op
             spec = jax.sharding.PartitionSpec(*([U] * (t.ndim - 1)), "model")
             return jax.lax.with_sharding_constraint(t, spec)

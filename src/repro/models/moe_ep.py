@@ -23,7 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.models.param import Box
 
 
@@ -160,7 +159,7 @@ def moe_apply_ep(cfg, p, x, mesh, *, data_axes=("data",)):
             aux = jax.lax.pmean(aux, "model")
         return y, aux
 
-    fn = jax_compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(da, None), P(None, None), P(da, None, "model"),

@@ -13,7 +13,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import jax_compat
 from repro.core.lora import lora_apply
 from repro.models import rglru
 from repro.models.layers import (attn_decode, attn_direct, attn_prefill,
@@ -149,7 +148,7 @@ def block_apply(cfg, p, x, positions, *, lora_layer, lora_idx, lora_ranks,
     h = x + a
     hn = norm_apply(p["norm2"], h, cfg.norm)
     if cfg.moe:
-        amesh = jax_compat.get_abstract_mesh()
+        amesh = jax.sharding.get_abstract_mesh()
         if cfg.moe_ep and "data" in amesh.axis_names:
             from repro.models.moe_ep import moe_apply_ep
             data_axes = tuple(a for a in ("pod", "data")
@@ -269,7 +268,7 @@ def prefill(cfg, params, tokens, *, prefix_embeds=None, lora=None,
     def body(carry, xs):
         x, aux = carry
         if cfg.seq_parallel and \
-                "model" in jax_compat.current_axis_names():
+                "model" in jax.sharding.get_abstract_mesh().axis_names:
             # sequence parallelism: the residual stream lives L-sharded over
             # the model axis; GSPMD turns the TP all-reduces into
             # reduce-scatter + all-gather pairs (half the bytes) and the

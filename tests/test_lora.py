@@ -122,3 +122,17 @@ def test_adapter_nbytes_scales_with_rank():
     assert abs(s64 / s8 - 8.0) < 1e-6
     # rank-64 q/k/v adapter of llama2-7b ~ 100 MiB (paper sec 2.3)
     assert 50e6 < s64 < 250e6
+
+
+def test_adapter_seed_is_a_stable_digest():
+    """Adapter weights are a pure function of (uid, seed): the derived seed
+    is a crc32 digest, identical in every process (str hash is salted)."""
+    spec = lora_lib.AdapterSpec("a0", rank=8, base_model="yi-9b", seed=3)
+    assert lora_lib.adapter_seed(spec) == 483136901
+    assert lora_lib.adapter_seed(
+        lora_lib.AdapterSpec("a0", rank=8, base_model="yi-9b")) == 2244297791
+    cfg = get_config("llama2-7b").smoke()
+    w1 = lora_lib.make_adapter_weights(cfg, spec)
+    w2 = lora_lib.make_adapter_weights(cfg, spec)
+    np.testing.assert_array_equal(np.asarray(w1["q"]["a"]),
+                                  np.asarray(w2["q"]["a"]))
