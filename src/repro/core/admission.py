@@ -11,6 +11,7 @@ handed `clock` and returns the admissions plus the serial time they cost.
 from __future__ import annotations
 
 import collections
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -71,6 +72,10 @@ class AdmissionPlane:
         if allocator is not None:
             allocator.on_free = self._note_pages_freed
         self.queue: collections.deque = collections.deque()
+        # why the last `admit` pass stopped: "empty" queue, no free "rows",
+        # the front request's "arrival" still ahead of the clock, or its
+        # "kv_pages" / "adapter_slots" could not be claimed
+        self.stop_reason = ""
         self.rows: List[Optional[RequestState]] = [None] * max_batch
         self.row_slot = np.full(max_batch, -1, np.int64)   # adapter pool slot
         self.row_pos = np.zeros(max_batch, np.int64)       # next decode pos
@@ -226,6 +231,7 @@ class AdmissionPlane:
         Returns (admitted, serial_ms): the serial prefill/stall time the
         admissions add to this iteration."""
         self.pages_freed = False
+        self.stop_reason = ""
         iter_ms = 0.0
         admitted = []
         while self.queue and self.free_row() is not None \
@@ -247,6 +253,7 @@ class AdmissionPlane:
                     self.rows[row] = None
                     st.row = -1
                     self.queue.appendleft(st)
+                    self.stop_reason = "kv_pages"
                     break
             resume = st.preempted
             chunked = self._chunk_admit(st)
@@ -264,7 +271,10 @@ class AdmissionPlane:
                 self.rows[row] = None
                 st.row = -1
                 self.queue.appendleft(st)
+                self.stop_reason = "adapter_slots"
                 break
+            if st.admit_s is None:
+                st.admit_s = time.perf_counter()
             if pages is not None:
                 # distinct lists: grow_row extends both (aliasing them
                 # would double-append every lazy growth claim)
@@ -322,6 +332,9 @@ class AdmissionPlane:
             self.peak_active_rows = max(
                 self.peak_active_rows,
                 sum(r is not None for r in self.rows))
+        if not self.stop_reason:
+            self.stop_reason = "empty" if not self.queue else \
+                "rows" if self.free_row() is None else "arrival"
         return admitted, iter_ms
 
     def _should_shed(self, st: RequestState, clock: float) -> bool:

@@ -33,9 +33,11 @@ step executes). Three entry points:
 
 `pipeline="perstep"` keeps the pre-pipeline behaviour (host sampling off
 full logits, per-step host→device token/position uploads, synchronous
-readback) as the benchmark baseline; `transfer_stats` counts host-link
-crossings on both paths so `benchmarks/bench_pipeline.py` can assert the
-reduction.
+readback) as a baseline. `transfer_stats` is the server's counter dict
+(`core/tracing.py`): host-link crossings and their bytes, decode steps,
+megasteps and prefills on both paths, and the nanoseconds blocked in the
+readback's `jax.device_get` (`readback_ns`); the server adds its own loop
+counters to the same dict.
 
 The timeline plane (InferenceServer) never touches arrays; the admission
 plane never touches jit. Timing-only simulations simply do not construct a
@@ -44,6 +46,7 @@ backend.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -52,6 +55,7 @@ import numpy as np
 
 from repro.analysis import retrace, sanitizers
 from repro.configs.base import ModelConfig
+from repro.core import tracing
 from repro.core.lora import DevicePool, HostLoRAStore, StagingCache
 from repro.models import model as model_lib
 from repro.models.param import split
@@ -68,6 +72,21 @@ def bucket(n: int, lo: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def read_back(stats: Dict[str, int], x) -> np.ndarray:
+    """Block on the device-to-host copy of `x`, counted as one `d2h` and
+    timed into `readback_ns` under a `serve.readback` span."""
+    t0 = time.perf_counter_ns()
+    with jax.profiler.TraceAnnotation("serve.readback"):
+        # lint: allow-host-sync — the readback IS the designed d2h point:
+        # the pipeline drains `readback_depth` megasteps behind dispatch,
+        # the perstep baseline blocks on every step
+        arr = np.asarray(jax.device_get(x))
+    stats["readback_ns"] += time.perf_counter_ns() - t0
+    stats["d2h"] += 1
+    stats["d2h_bytes"] += arr.nbytes
+    return arr
 
 
 def _select_rows(new_tree, old_tree, active):
@@ -178,14 +197,13 @@ class DecodePipeline:
 
     def _drain_one(self):
         toks, entries = self._pending.pop(0)
-        # lint: allow-host-sync — the drain IS the designed d2h point: it
-        # lands `readback_depth` megasteps behind dispatch, off the hot path
-        arr = np.asarray(jax.device_get(toks))
-        self.stats["d2h"] += 1
-        self.stats["d2h_bytes"] += arr.nbytes
+        arr = read_back(self.stats, toks)
+        now = time.perf_counter()
         for st, col, n in entries:
             vals = [int(arr[col])] if arr.ndim == 1 \
                 else [int(v) for v in arr[:n, col]]
+            if st.first_token_s is None and vals:
+                st.first_token_s = now
             st.generated.extend(vals)
             st.pending_tokens -= n
 
@@ -197,7 +215,8 @@ class DecodePipeline:
 class NumericsBackend:
     def __init__(self, cfg: ModelConfig, *, kernel: str, max_batch: int,
                  cache_slots: int, store: HostLoRAStore, pool: DevicePool,
-                 params=None, seed: int = 0, pipeline: str = "fused",
+                 stats: Dict[str, int], params=None, seed: int = 0,
+                 pipeline: str = "fused",
                  megastep: int = MEGASTEP_MAX, temperature: float = 0.0,
                  staging_slots: int = 16, memory: str = "dense",
                  page_size: int = 32, allocator=None):
@@ -253,14 +272,11 @@ class NumericsBackend:
         self.cache = cache_lib.zeros_paged(
             row_cache, allocator.n_pages, page_size) if self.paged \
             else cache_lib.zeros_like_batched(row_cache, max_batch)
-        self.transfer_stats: Dict[str, int] = {
-            "h2d": 0, "h2d_bytes": 0, "d2h": 0, "d2h_bytes": 0,
-            "decode_steps": 0, "megasteps": 0, "megastep_iters": 0,
-            "prefills": 0, "prefill_chunks": 0}
+        self.transfer_stats = stats       # the owning server's counters
         self.pipe = DecodePipeline(max_batch, seed + 1, self.transfer_stats,
                                    bt_width=self.bt_width)
-        self.staging = StagingCache(staging_slots,
-                                    on_upload=self._count_upload)
+        self.staging = StagingCache(staging_slots, on_upload=functools.partial(
+            tracing.count_upload, self.transfer_stats))
         mask_ok = model_lib.supports_write_mask(cfg)
         self._decode_legacy_jit = jax.jit(
             functools.partial(self._decode_legacy_fn, cfg, self._mode_str()),
@@ -292,10 +308,6 @@ class NumericsBackend:
 
     def _mode_str(self):
         return "bgmv" if self.kernel == "bgmv" else "mbgmv"
-
-    def _count_upload(self, nbytes: int):
-        self.transfer_stats["h2d"] += 1
-        self.transfer_stats["h2d_bytes"] += nbytes
 
     def flush_readback(self):
         """Drain every queued async token readback (end of run, or before
@@ -479,7 +491,10 @@ class NumericsBackend:
                     donate_argnums=donate)
             (toks_out, self.cache, pipe.last_tok, pipe.pos, pipe.target,
              pipe.rng) = self._prefill_jit[key](*args)
+        now = time.perf_counter()
         for st in states:
+            if st.prefill_s is None:
+                st.prefill_s = now
             if not st.preempted:
                 st.token_times_ms.append(st.first_token_ms)
         # resumed rows re-sample a token they already emitted — exclude
@@ -615,6 +630,8 @@ class NumericsBackend:
                 self.params, jnp.asarray(toks), start_j, clen_j, row, plen,
                 tgt, self.cache, pipe.last_tok, pipe.pos, pipe.target,
                 pipe.rng, lora, jnp.asarray(ids))
+            if st.prefill_s is None:
+                st.prefill_s = time.perf_counter()
             self._observe_trace("prefill_chunk_final", self._chunk_jit[key])
             pipe.stash(toks_out, [(st, 0, 1)])
             if self.pipeline == "perstep":
@@ -777,11 +794,9 @@ class NumericsBackend:
         logits, self.cache = self._decode_legacy_jit(
             self.params, self.cache, jnp.asarray(toks), jnp.asarray(pos),
             lora)
-        # lint: allow-host-sync — the perstep pipeline is the synchronous
-        # legacy baseline; blocking readback each step is its defining cost
-        new = np.asarray(sample(logits[:, -1]))
-        self.transfer_stats["d2h"] += 1
-        self.transfer_stats["d2h_bytes"] += new.nbytes
+        # the synchronous legacy baseline: blocking readback each step is
+        # its defining cost
+        new = read_back(self.transfer_stats, sample(logits[:, -1]))
         for st in ready:
             st.generated.append(int(new[st.row]))
 

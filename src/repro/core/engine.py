@@ -25,9 +25,14 @@ Modes: cached | ondemand | slora | caraserve.  Kernels: bgmv | mbgmv.
 from __future__ import annotations
 
 import collections
+import functools
+import time
 from typing import List, Optional
 
+import jax
+
 from repro.configs.base import ModelConfig
+from repro.core import tracing
 from repro.core.admission import AdmissionPlane
 from repro.core.backend import NumericsBackend, bucket as _bucket
 from repro.core.cold_start import ColdStartManager
@@ -68,6 +73,12 @@ class InferenceServer:
         self.link_policy = link_policy
         self.tm = TimingModel(cfg, hw)
         self.store = HostLoRAStore(cfg)
+        # the server's counters (`core/tracing.py`): the backend counts
+        # transfers and steps into this dict and exposes it as its
+        # `transfer_stats`; `step` adds its wall time and the GC inside it
+        self.transfer_stats = tracing.new_stats()
+        self._steps = 0
+        tracing.install_gc_hook()
         n_slots = pool_slots or max(cfg.lora.n_slots, max_batch)
         # memory plane: "paged" = block-table KV + unified KV/LoRA page
         # allocator (fused numerics on families with the uniform layered
@@ -99,7 +110,10 @@ class InferenceServer:
             self.allocator = None
         self.pool = DevicePool(cfg, n_slots=n_slots, materialize=numerics,
                                allocator=self.allocator,
-                               page_bytes=self.page_bytes)
+                               page_bytes=self.page_bytes,
+                               on_upload=functools.partial(
+                                   tracing.count_upload,
+                                   self.transfer_stats))
         self.cold = ColdStartManager(self.tm, self.store, self.pool, mode,
                                      link_policy=link_policy)
         # KV over-subscription: admission claims prompt pages only
@@ -141,7 +155,8 @@ class InferenceServer:
                                         shed_late_slo=shed_late_slo)
         self.backend = NumericsBackend(
             cfg, kernel=kernel, max_batch=max_batch, cache_slots=cache_slots,
-            store=self.store, pool=self.pool, params=params, seed=seed,
+            store=self.store, pool=self.pool, stats=self.transfer_stats,
+            params=params, seed=seed,
             pipeline=pipeline, megastep=megastep, temperature=temperature,
             staging_slots=staging_slots, memory=memory, page_size=page_size,
             allocator=self.allocator) if numerics else None
@@ -224,7 +239,7 @@ class InferenceServer:
                 f"request {req.rid}: prompt is {req.prompt_len} tokens but "
                 f"each KV-cache row holds {self.cache_slots} slots; raise "
                 "cache_slots or truncate the prompt before submitting")
-        st = RequestState(req)
+        st = RequestState(req, submit_s=time.perf_counter())
         self.states.append(st)
         self.admission.enqueue(st)
         return st
@@ -321,7 +336,24 @@ class InferenceServer:
         """One continuous-batching iteration; advances the virtual clock.
         When the iteration is empty (everything waits on a future event) the
         clock jumps to the next actionable time, clamped to `horizon_ms`
-        (the caller's next arrival) so admissions are never skipped over."""
+        (the caller's next arrival) so admissions are never skipped over.
+
+        Traced as a `serve.step` step span with `serve.*` children; its
+        wall time and the garbage collection inside it are added to
+        `transfer_stats` (`step_ns`, `gc_ns`, `gc_runs`)."""
+        t0 = time.perf_counter_ns()
+        gc0 = tracing.gc_totals()
+        with jax.profiler.StepTraceAnnotation("serve.step",
+                                              step_num=self._steps):
+            self._step(horizon_ms)
+        self._steps += 1
+        gc_ns, gc_runs = tracing.gc_totals()
+        stats = self.transfer_stats
+        stats["gc_ns"] += gc_ns - gc0[0]
+        stats["gc_runs"] += gc_runs - gc0[1]
+        stats["step_ns"] += time.perf_counter_ns() - t0
+
+    def _step(self, horizon_ms: Optional[float]):
         # 0. uploads finished by now land (queued for the flip below)
         self.cold.poll(self.clock)
 
@@ -391,27 +423,30 @@ class InferenceServer:
         # the decode step (piggyback batching) — its chunk pages are
         # claimed here, chunk-by-chunk, with the same victim fallback as
         # lazy decode growth. Rows in phase "prefill" never decode.
-        chunk_st, chunk_n = self._plan_chunk(iter_ms)
-        ready = [r for r in rows
-                 if r is not None and r.phase != "prefill"
-                 and r.ready_ms <= self.clock + iter_ms
-                 and not r.done]
-        for r in ready:
-            if r.phase == "loading":
-                r.phase = "decode"
-        ready = self._ensure_pages(ready)
-        if chunk_st is not None and chunk_st.row < 0:
-            chunk_st, chunk_n = None, 0   # preempted by decode growth above
-        if ready:
+        with jax.profiler.TraceAnnotation("serve.plan"):
+            chunk_st, chunk_n = self._plan_chunk(iter_ms)
+            ready = [r for r in rows
+                     if r is not None and r.phase != "prefill"
+                     and r.ready_ms <= self.clock + iter_ms
+                     and not r.done]
+            for r in ready:
+                if r.phase == "loading":
+                    r.phase = "decode"
+            ready = self._ensure_pages(ready)
+            if chunk_st is not None and chunk_st.row < 0:
+                chunk_st, chunk_n = None, 0   # preempted by growth above
             plan = self._plan_megastep(ready, horizon_ms) \
-                if (self.backend and not admitted and iter_ms == 0.0
-                    and chunk_st is None) \
+                if (ready and self.backend and not admitted
+                    and iter_ms == 0.0 and chunk_st is None) \
                 else None
+        if ready:
             if plan is not None:
                 K, nsteps, per_iter = plan
-                self.backend.megastep(ready, nsteps, K,
-                                      self.admission.row_slot,
-                                      self.admission.row_pages)
+                with jax.profiler.TraceAnnotation(
+                        "serve.megastep", K=K, rows=len(ready)):
+                    self.backend.megastep(ready, nsteps, K,
+                                          self.admission.row_slot,
+                                          self.admission.row_pages)
                 # bill exactly like K single steps: the batch shrinks as
                 # rows hit their stop target, each surviving row gets its
                 # token timestamp at that iteration's end
@@ -452,9 +487,11 @@ class InferenceServer:
                         len(cpu_ranks)
                 iter_ms += dec_ms
                 if self.backend:
-                    self.backend.decode(ready, self.admission.row_slot,
-                                        self.admission.row_pos,
-                                        self.admission.row_pages)
+                    with jax.profiler.TraceAnnotation("serve.decode",
+                                                      rows=len(ready)):
+                        self.backend.decode(ready, self.admission.row_slot,
+                                            self.admission.row_pos,
+                                            self.admission.row_pages)
                 else:
                     for r in ready:
                         r.generated.append(0)
@@ -483,12 +520,13 @@ class InferenceServer:
                 else self.clock + IDLE_TICK_MS
 
         # 4. retire finished requests
-        for row, st in enumerate(rows):
-            if st is not None and st.done:
-                st.finish_ms = st.token_times_ms[-1] if st.token_times_ms \
-                    else self.clock
-                st.phase = "done"
-                self.admission.release(row)
+        with jax.profiler.TraceAnnotation("serve.retire"):
+            for row, st in enumerate(rows):
+                if st is not None and st.done:
+                    st.finish_ms = st.token_times_ms[-1] \
+                        if st.token_times_ms else self.clock
+                    st.phase = "done"
+                    self.admission.release(row)
 
         # 4b. pages freed this step (retires, preemptions, adapter sheds —
         # the allocator's on_free hook sets the flag) un-defer queued work
@@ -511,7 +549,12 @@ class InferenceServer:
         numerics backend: batched prefill for fresh admissions and
         recompute resumes (one padded call rebuilds a preempted row's KV
         bitwise), page re-upload for swap resumes."""
-        admitted, iter_ms = self.admission.admit(self.clock)
+        adm = self.admission
+        span = jax.profiler.TraceAnnotation("serve.admit",
+                                            queue=len(adm.queue))
+        with span:
+            admitted, iter_ms = adm.admit(self.clock)
+            span.set_metadata(admitted=len(admitted), stop=adm.stop_reason)
         if admitted and self.allocator is not None:
             self.peak_oversub = max(self.peak_oversub, self.oversub_ratio())
         if admitted:
@@ -537,7 +580,10 @@ class InferenceServer:
                     elif st.kv_pages:
                         self.backend.clear_pages(st.kv_pages)
                 if mono or recs:
-                    self.backend.prefill_admitted(mono + recs)
+                    with jax.profiler.TraceAnnotation(
+                            "serve.prefill", rids=" ".join(
+                                str(st.req.rid) for st in mono + recs)):
+                        self.backend.prefill_admitted(mono + recs)
             else:
                 for st in fresh:
                     if st.phase == "prefill":
@@ -655,8 +701,10 @@ class InferenceServer:
         start = st.prefill_pos
         final = start + n >= st.req.prompt_len
         if self.backend:
-            self.backend.prefill_chunk(st, adm.row_pages[st.row], start, n,
-                                       final)
+            with jax.profiler.TraceAnnotation("serve.prefill",
+                                              rids=str(st.req.rid)):
+                self.backend.prefill_chunk(st, adm.row_pages[st.row], start,
+                                           n, final)
         st.prefill_pos = start + n
         if not final:
             return

@@ -158,8 +158,9 @@ def lora_apply(x, lora_layer, target, lora_idx, ranks, mode="bgmv",
     if lora_layer is None or target not in lora_layer:
         return 0.0
     ab = lora_layer[target]
-    return lora_delta_ref(x, ab["a"], ab["b"], lora_idx, ranks=ranks,
-                          mode=mode, rank_block=rank_block)
+    with jax.named_scope("lora_delta"):
+        return lora_delta_ref(x, ab["a"], ab["b"], lora_idx, ranks=ranks,
+                              mode=mode, rank_block=rank_block)
 
 
 # --------------------------------------------------------- host store ----
@@ -236,8 +237,9 @@ class StagingCache:
             self._order.remove(stale)
             del self._entries[stale]
         w = store.weights(uid)
-        ent = {t: {"a": jnp.asarray(w[t]["a"]), "b": jnp.asarray(w[t]["b"])}
-               for t in w}
+        with jax.profiler.TraceAnnotation("serve.adapter_upload", uid=uid):
+            ent = {t: {"a": jnp.asarray(w[t]["a"]),
+                       "b": jnp.asarray(w[t]["b"])} for t in w}
         if self._on_upload is not None:
             self._on_upload(sum(int(w[t][ab].nbytes) for t in w
                                 for ab in ("a", "b")))
@@ -266,12 +268,16 @@ class DevicePool:
     from the unified KV/LoRA pool: reserve claims them, evict/release frees
     them, and `shed_cold` lets a KV-hungry admission reclaim the pages of
     cold (ready, unpinned) residents LRU-first. Without an allocator the
-    pool behaves exactly as before (a static reservation)."""
+    pool behaves exactly as before (a static reservation).
+
+    ``on_upload(nbytes)`` lets the owner count the host-link transfer each
+    materialized reservation costs."""
 
     def __init__(self, cfg: ModelConfig, n_slots: Optional[int] = None,
                  materialize: bool = True, allocator=None,
-                 page_bytes: int = 0):
+                 page_bytes: int = 0, on_upload=None):
         self.cfg = cfg
+        self._on_upload = on_upload
         self.n_slots = n_slots or cfg.lora.n_slots
         self.materialize = materialize
         self.pool = pool_init(cfg, self.n_slots) if materialize else None
@@ -347,7 +353,13 @@ class DevicePool:
             self.slot_pages[slot] = self.allocator.claim(
                 need, f"adapter:{uid}")
         if self.materialize:
-            self.pool = pool_insert(self.pool, self.cfg, weights, slot, rank)
+            with jax.profiler.TraceAnnotation("serve.adapter_upload",
+                                              uid=uid):
+                self.pool = pool_insert(self.pool, self.cfg, weights, slot,
+                                        rank)
+            if self._on_upload is not None:
+                self._on_upload(sum(int(ab[k].nbytes) for ab in
+                                    weights.values() for k in ("a", "b")))
         self.slot_uid[slot] = uid
         self.slot_ready[slot] = False
         self._touch(slot)

@@ -314,13 +314,14 @@ def paged_attn_decode(q, cache, block_table, pos, window=None):
     q: (B, 1, H, hd); cache leaves are the page pools; block_table (B, W);
     pos: (B,). Routes per `paged_attn_impl()`; windowed attention always
     takes the gather path (the kernel has no sliding-window mask)."""
-    if window is None and paged_attn_impl() == "pallas":
-        from repro.kernels.paged import paged_attention
-        out = paged_attention(q[:, 0], cache["k"], cache["v"],
-                              cache["pos"], block_table, pos)
-        return out[:, None]
-    ck, cv, cpos = paged_kv_for_attn(cache, block_table)
-    return attn_decode(q, ck, cv, cpos, pos, window=window)
+    with jax.named_scope("paged_attn_decode"):
+        if window is None and paged_attn_impl() == "pallas":
+            from repro.kernels.paged import paged_attention
+            out = paged_attention(q[:, 0], cache["k"], cache["v"],
+                                  cache["pos"], block_table, pos)
+            return out[:, None]
+        ck, cv, cpos = paged_kv_for_attn(cache, block_table)
+        return attn_decode(q, ck, cv, cpos, pos, window=window)
 
 
 def paged_kv_for_attn(cache, block_table):

@@ -28,6 +28,8 @@ class RequestState:
     row: int = -1                      # batch row in the engine
     phase: str = "queued"              # queued | loading | prefill | decode | done
     generated: List[int] = dataclasses.field(default_factory=list)
+    # every `*_ms` field is on `TimingModel`'s virtual clock (simulated
+    # milliseconds, a prediction); the `*_s` stamps below are wall time
     first_token_ms: Optional[float] = None
     finish_ms: Optional[float] = None
     token_times_ms: List[float] = dataclasses.field(default_factory=list)
@@ -36,6 +38,14 @@ class RequestState:
     ready_ms: float = 0.0              # decode may include this request after
     load_finish_ms: Optional[float] = None  # adapter upload completion
     flip_ms: Optional[float] = None    # CPU-assist -> device pool switch
+    # host wall clock (`time.perf_counter()` seconds), each set once at its
+    # first occurrence: `InferenceServer.submit`; a batch row from
+    # `AdmissionPlane.admit` (a resume keeps the first); the prefill (or
+    # final chunk) program dispatched; the first token in `generated`
+    submit_s: Optional[float] = None
+    admit_s: Optional[float] = None
+    prefill_s: Optional[float] = None
+    first_token_s: Optional[float] = None
     # tokens sampled on device but not yet read back to `generated` (the
     # numerics plane's async readback queue); the engine's control flow
     # counts them via `issued` so completion never waits on a host sync
