@@ -78,6 +78,8 @@ class KernelModel:
     kernel_params: List[str]        # positional ref params of the kernel
     kernel_ast: Optional[ast.FunctionDef]
     ast_line_base: int              # kernel_ast lineno 1 == this file line
+    in_groups: List[int]            # in_specs per input param (a param
+                                    # bound to a sequence gets that many)
 
     # ---------------------------------------------------------------- ast --
     def abs_line(self, node: ast.AST) -> int:
@@ -88,7 +90,7 @@ class KernelModel:
     def param_roles(self) -> Optional[Dict[str, str]]:
         """Map kernel param name -> scalar|input|output|scratch, or None if
         the signature does not line up with the captured specs."""
-        nsp, ni = self.num_scalar_prefetch, len(self.in_specs)
+        nsp, ni = self.num_scalar_prefetch, len(self.in_groups)
         no, ns = len(self.out_specs), len(self.scratch)
         if len(self.kernel_params) != nsp + ni + no + ns:
             return None
@@ -202,6 +204,14 @@ def _flat_specs(specs) -> List[pl.BlockSpec]:
         specs, is_leaf=lambda x: isinstance(x, pl.BlockSpec)))
 
 
+def _spec_groups(specs) -> List[int]:
+    """BlockSpecs per top-level entry of `specs`: Pallas hands the kernel
+    one ref, or one sequence of refs, per entry."""
+    if isinstance(specs, (list, tuple)):
+        return [len(_flat_specs(s)) for s in specs]
+    return [len(_flat_specs(specs))]
+
+
 @contextmanager
 def capture(into: List[KernelModel], *, name: str = "", case: str = ""):
     """Patch ``pl.pallas_call`` so wrapper invocations append a
@@ -213,12 +223,14 @@ def capture(into: List[KernelModel], *, name: str = "", case: str = ""):
         if grid_spec is not None:
             g = tuple(grid_spec.grid)
             nsp = int(getattr(grid_spec, "num_scalar_prefetch", 0) or 0)
+            groups = _spec_groups(grid_spec.in_specs)
             ins = _flat_specs(grid_spec.in_specs)
             outs = _flat_specs(grid_spec.out_specs)
             scratch = list(getattr(grid_spec, "scratch_shapes", ()) or ())
         else:
             g = tuple(grid)
             nsp = 0
+            groups = _spec_groups(in_specs)
             ins = _flat_specs(in_specs)
             outs = _flat_specs(out_specs)
             scratch = list(scratch_shapes or ())
@@ -228,7 +240,7 @@ def capture(into: List[KernelModel], *, name: str = "", case: str = ""):
 
         def runner(*operands):
             scalars = [np.asarray(o) for o in operands[:nsp]]
-            tensors = operands[nsp:]
+            tensors = jax.tree_util.tree_leaves(operands[nsp:])
             in_models = []
             for spec, op in zip(ins, tensors):
                 imap = _index_map(spec)
@@ -256,14 +268,20 @@ def capture(into: List[KernelModel], *, name: str = "", case: str = ""):
                 in_specs=in_models, out_specs=out_models,
                 scratch=[(tuple(int(d) for d in s.shape),
                           np.dtype(s.dtype)) for s in scratch],
-                kernel_params=params, kernel_ast=kast, ast_line_base=kline)
-            # bind ref param names to specs (for messages)
+                kernel_params=params, kernel_ast=kast, ast_line_base=kline,
+                in_groups=groups)
+            # bind ref param names to specs (for messages); a param bound
+            # to a sequence of specs names its leaves param[0], param[1], ...
             roles = model.param_roles()
             if roles is not None:
-                for i, sm in enumerate(in_models):
-                    sm.name = params[nsp + i]
+                leaves = iter(in_models)
+                for gi, size in enumerate(groups):
+                    for k in range(size):
+                        sm = next(leaves)
+                        sm.name = params[nsp + gi] + (
+                            f"[{k}]" if size > 1 else "")
                 for i, sm in enumerate(out_models):
-                    sm.name = params[nsp + len(in_models) + i]
+                    sm.name = params[nsp + len(groups) + i]
             into.append(model)
             return jax.tree_util.tree_unflatten(
                 jax.tree_util.tree_structure(out_shape),

@@ -294,12 +294,13 @@ def cache_write_token_paged(cache, k_t, v_t, pos, block_table,
 
 
 # Decode-attention implementation over the paged layout. "auto" picks the
-# Pallas paged-attention kernel (kernels/paged.py) on TPU backends — the
-# DMA engine pulls K/V page tiles through the scalar-prefetched block
-# table, so the dense gathered view below never materializes — and the
-# pure-jnp gather path elsewhere (it is also the bitwise reference the
-# kernel is validated against). Tests/benches override the module global
-# to force one side of the equivalence.
+# Pallas paged-attention kernel (kernels/paged.py) on TPU backends — grid
+# (B, W / n): each step's DMA pulls n whole pages, every KV head of each,
+# through the scalar-prefetched block table, with n set by the page's bytes,
+# so the dense gathered view below never materializes — and the pure-jnp
+# gather path elsewhere (it is also the bitwise reference the kernel is
+# validated against). Tests/benches override the module global to force
+# one side of the equivalence.
 PAGED_ATTN_IMPL = "auto"          # auto | pallas | gather
 
 

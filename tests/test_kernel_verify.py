@@ -45,7 +45,7 @@ def test_index_map_evaluates_with_scalars(models):
     m = models["paged_attention"]
     # the K-page spec gathers through the prefetched block table
     kspec = m.in_specs[1]
-    c = m.eval_index(kspec, (0, 0, 0))
+    c = m.eval_index(kspec, (0,) * len(m.grid))
     assert all(isinstance(x, int) for x in c)
 
 

@@ -159,6 +159,9 @@ def _paged_case(seed, B, H, KV, hd, ps, P, W):
     (4, 8, 4, 32, 16, 12, 4),        # partial fills, unclaimed pages
     (2, 4, 4, 64, 32, 6, 2),         # MHA-style (H == KV groups of 1)
     (3, 8, 2, 16, 8, 24, 5),         # deep tables, big GQA group
+    (2, 16, 16, 96, 32, 12, 4),      # hd 96 (off 128 lanes), 16 KV heads
+    (2, 16, 2, 32, 16, 10, 4),       # GQA group 8
+    (2, 16, 16, 128, 32, 8, 3),      # pages too big to take two a step
 ])
 def test_paged_attention_matches_oracle(B, H, KV, hd, ps, P, W):
     from repro.kernels.paged import paged_attention
@@ -181,6 +184,45 @@ def test_paged_attention_ignores_foreign_pages():
     want = np.asarray(ref.paged_attention_ref(q, k2, v, pp, bt, pos))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(got[1:], base[1:], atol=2e-5, rtol=2e-5)
+
+
+def test_paged_attention_ignores_page_zero_behind_unclaimed_entries():
+    """An unclaimed table entry is clamped to physical page 0, here row 0's
+    first page with valid positions and huge keys and values; rows 1 and 2
+    must not see it, whether the entry shares a grid step with a claimed
+    page (row 1's second entry) or fills a step alone."""
+    from repro.kernels.paged import paged_attention
+    B, H, KV, hd, ps, P, W = 3, 4, 2, 16, 8, 6, 3
+    rng = np.random.default_rng(11)
+    q, k, v = (jnp.asarray(rng.normal(size=s), jnp.float32)
+               for s in [(B, H, hd)] + [(P, KV, ps, hd)] * 2)
+    bt = jnp.asarray([[0, 1, -1], [2, -1, -1], [3, 4, 5]], jnp.int32)
+    pos = jnp.asarray([12, 5, 20], jnp.int32)
+    slots = np.arange(ps)
+    fill = {0: (0, 8), 1: (8, 13), 2: (0, 6), 3: (0, 8), 4: (8, 16),
+            5: (16, 21)}
+    pp = np.full((P, ps), -1, np.int32)
+    for pg, (lo, hi) in fill.items():
+        pp[pg] = np.where(slots < hi - lo, slots + lo, -1)
+    pp = jnp.asarray(pp)
+    base = np.asarray(paged_attention(q, k, v, pp, bt, pos))
+    k2, v2 = k.at[0].mul(100.0), v.at[0].add(50.0)
+    got = np.asarray(paged_attention(q, k2, v2, pp, bt, pos))
+    want = np.asarray(ref.paged_attention_ref(q, k2, v2, pp, bt, pos))
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(got[1:], base[1:], atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("page_bytes,width,n", [
+    (32 * 32 * 96 * 2, 32, 2),       # Phi-3-mini: 32 KV heads, hd 96, bf16
+    (4 * 32 * 128 * 2, 32, 8),       # Yi-9B: 4 KV heads, hd 128, bf16
+    (1024, 5, 4),                    # tiny pages: capped by the table
+    (1024, 1, 1),
+    (512 * 1024, 32, 1),             # one page is already a full step
+])
+def test_pages_per_step(page_bytes, width, n):
+    from repro.kernels.paged import pages_per_step
+    assert pages_per_step(page_bytes, width) == n
 
 
 # ---------------------------------------------- conformance sweep (paged) ----
@@ -209,15 +251,16 @@ def _edge_case(seed, B, H, KV, hd, ps, P, W):
 @pytest.mark.parametrize("W", [2, 5])
 @pytest.mark.parametrize("group", [1, 4])
 @pytest.mark.parametrize("B", [1, 4])
-def test_paged_attention_conformance_sweep(ps, W, group, B):
+@pytest.mark.parametrize("KV", [2, 8])
+def test_paged_attention_conformance_sweep(ps, W, group, B, KV):
     """Interpret-mode kernel == jnp oracle across (page size, table width,
-    GQA group, batch) including all-unclaimed rows, pos=0, and a claimed
-    fully-masked page — the inputs whose garbage paths only the mask-aware
-    online softmax keeps at exactly zero."""
+    GQA group, batch, KV heads in the all-heads page block) including
+    all-unclaimed rows, pos=0, and a claimed fully-masked page — the inputs
+    whose garbage paths only the mask-aware online softmax keeps at exactly
+    zero."""
     from repro.kernels.paged import paged_attention
-    KV = 2
     H, hd, P = KV * group, 16, W * B + 2
-    q, k, v, pp, bt, pos = _edge_case(hash((ps, W, group, B)) % 251,
+    q, k, v, pp, bt, pos = _edge_case(hash((ps, W, group, B, KV)) % 251,
                                       B, H, KV, hd, ps, P, W)
     got = np.asarray(paged_attention(q, k, v, pp, bt, pos))
     want = np.asarray(ref.paged_attention_ref(q, k, v, pp, bt, pos))

@@ -55,12 +55,16 @@ def _shapes(tree, sharding):
         tree)
 
 
-@pytest.mark.parametrize("H,KV", [(32, 4), (32, 32)],
-                         ids=["yi9b-gqa8", "mha"])
-def test_paged_attention_compiles_for_v5e(one_chip, H, KV):
-    """Decode widths of Yi-9B (GQA group 8) and of an MHA model (group 1):
-    batch 8, head_dim 128, 32-slot pages, 64-page block tables, bf16."""
-    B, hd, ps, W, P = 8, 128, 32, 64, 520
+@pytest.mark.parametrize("H,KV,hd,B,W,P", [
+    (32, 4, 128, 8, 64, 520),
+    (32, 32, 128, 8, 64, 520),
+    (32, 32, 96, 4, 32, 152),
+], ids=["yi9b-gqa8", "mha", "phi3mini-hd96"])
+def test_paged_attention_compiles_for_v5e(one_chip, H, KV, hd, B, W, P):
+    """Decode widths of Yi-9B (GQA group 8), of an MHA model (group 1) and
+    of Phi-3-mini (MHA at head_dim 96, off the 128-lane tiling; 4 rows of
+    32-page tables): 32-slot pages, bf16."""
+    ps = 32
     sd = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     fn = jax.jit(functools.partial(paged_attention, interpret=False))
     compiled = fn.lower(
