@@ -23,14 +23,13 @@ prompts and tokens. The control's run has to come out not correct.
 """
 from __future__ import annotations
 
-import importlib
 import json
 import pathlib
 from typing import Dict, List
 
 import numpy as np
 
-from bench import traffic
+from bench import arch, traffic
 
 HERE = pathlib.Path(__file__).resolve().parent
 TOKENS = 512
@@ -46,7 +45,7 @@ def limits(config: str) -> dict:
 
 
 def reference(conf: dict, weights, seq: int, n_rows: int, quant: str = ""):
-    mod = importlib.import_module(f"bench.reference.{conf['reference']}")
+    mod = arch.load(conf, "reference")
     return mod.Reference(conf, weights, seq, n_rows, quant=quant)
 
 

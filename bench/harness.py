@@ -483,8 +483,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         if len(s) >= 2:
             tpot.append((s[-1] - s[0]) / (len(s) - 1) * 1e3)
     out_tokens = sum(sum(1 for t in r.stamps if t < t_end) for r in recs)
-    values = {"ttft_p90_ms": percentile(ttft, 90) if ttft else None,
-              "tpot_p50_ms": percentile(tpot, 50) if tpot else None,
+    values = {"tpot_p50_ms": percentile(tpot, 50) if tpot else None,
               "tpot_p90_ms": percentile(tpot, 90) if tpot else None,
               "out_tok_s": out_tokens / window,
               "setup_s": setup_s}
@@ -535,7 +534,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                 f"pair with the {len(calls)} calls dispatched")
             progs = None
         ctx = dict(conf=conf, mix=mix, peak=pk, trace=tr, lo=lo, hi=hi,
-                   recs=recs, t_end=t_end, calls=calls, programs=progs,
+                   recs=recs, t_end=t_end, t_drained=t_drained,
+                   calls=calls, programs=progs,
                    stats={k: m1["stats"][k] - m0["stats"][k]
                           for k in m1["stats"]},
                    step_s=m1["step_s"] - m0["step_s"],

@@ -1,11 +1,15 @@
 """Kernel (`kernels/paged.py`): the paged decode attention kernel's share
 of its roofline: the larger of (bytes it must move / HBM bandwidth) and
 (FLOPs / bf16 peak), over the kernel's device time in the traced stretch,
-in percent. The kernel is the custom call (Pallas) inside the decode and
-megastep programs; bytes and FLOPs count each row's real context
-(`bench/flops.py`), not its padded pages. Device trace."""
+in percent. The kernel is the custom call (Pallas) named
+`paged_attn_decode*` inside the decode and megastep programs, so another
+kernel in those programs adds nothing to its time; bytes and FLOPs count
+each row's real context (`bench/flops.py`), not its padded pages. Device
+trace."""
 from bench import flops
 from bench import trace as trace_lib
+
+KERNEL = "paged_attn_decode"
 
 
 def read(ctx):
@@ -14,7 +18,8 @@ def read(ctx):
     conf, pk = ctx["conf"], ctx["peak"]
     _, spans = trace_lib.step_ns(ctx["programs"], ctx["calls"],
                                  ("decode", "megastep"))
-    kernel = trace_lib.ops_inside(ctx["trace"], 0, spans, "custom-call")
+    kernel = trace_lib.ops_inside(ctx["trace"], 0, spans, "custom-call",
+                                  prefix=KERNEL)
     ns = trace_lib.total(kernel)
     ctxs = [c for call in ctx["calls"] if call[0] != "prefill"
             for c, _ in call[1]]

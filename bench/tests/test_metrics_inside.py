@@ -17,6 +17,7 @@ STAMPED = ("arrival_lag_p90_ms", "queued_p90_ms", "first_token_lag_p90_ms",
            "prefill_readback_lag_p90_ms")
 COUNTED = ("loop_host_ms_per_iter", "gc_ms_per_s")
 NEW = STAMPED + COUNTED
+CLIENT = ("client_ttft_p90_ms",)
 
 
 def read(name, ctx):
@@ -48,9 +49,24 @@ def rec(due, lags, cold=False, stamped=True):
     return r
 
 
-def ctx_of(recs, stats=None, lo=0, hi=int(2e9), t_end=100.0):
-    return {"recs": recs, "t_end": t_end, "stats": stats or {},
-            "lo": lo, "hi": hi}
+def ctx_of(recs, stats=None, lo=0, hi=int(2e9), t_end=100.0,
+           t_drained=None):
+    return {"recs": recs, "t_end": t_end, "t_drained": t_drained or t_end,
+            "stats": stats or {}, "lo": lo, "hi": hi}
+
+
+def test_client_ttft_p90_on_synthetic_records():
+    """Every request due in the window counts, one without a first token
+    as waiting until the drain ended; one due after the window does not."""
+    recs = [rec(float(i), (0.01, 0.02, 0.01 * i)) for i in range(9)]
+    late = rec(9.0, (0.0, 0.0, 0.0))
+    late.stamps = []
+    after = rec(12.0, (5.0, 5.0, 5.0))
+    ctx = ctx_of(recs + [late, after], t_end=11.0, t_drained=19.5)
+    want = [30.0 + 10.0 * i for i in range(9)] + [10500.0]
+    got = read("client_ttft_p90_ms", ctx)
+    assert got == pytest.approx(float(np.percentile(want, 90)))
+    assert read("client_ttft_p90_ms", ctx_of([after], t_end=11.0)) is None
 
 
 def test_request_lags_on_synthetic_records():
@@ -135,13 +151,13 @@ def test_traced_tiny_run_reads_every_new_metric():
     cell = "yi9b.zipf64-poisson"
     b["per_layer"] = [
         {"name": n, "unit": "ms", "better": "lower", "source": "host_clock",
-         "layer": "event loop", "moves": "ttft_p90_ms", "workloads": [cell]}
-        for n in NEW]
+         "layer": "event loop", "moves": "tpot_p90_ms", "workloads": [cell]}
+        for n in NEW + CLIENT]
     conf = tiny.config("yi-9b-24l")
     mix = tiny.mix("zipf64-poisson")
     out = harness.run(cell, 5, 2.0, True, time.perf_counter(), bench=b,
                       configs={"yi-9b-24l": conf},
                       mixes={"zipf64-poisson": mix},
                       limits={"logit_gap": {"limit": 0.06}}, peak=tiny.PEAK)
-    for n in NEW:
+    for n in NEW + CLIENT:
         assert out["metrics"][n]["value"] >= 0.0, n

@@ -71,6 +71,19 @@ def test_ops_by_opcode_and_label():
     assert all("while" not in name for name, _ in top)
 
 
+def test_ops_by_instruction_prefix():
+    kernel = "%paged_attn_decode.11 = bf16[4,32,1,96]{3,2,1,0} custom-call("
+    other = "%grouped_matmul.2 = bf16[8,64]{1,0} custom-call("
+    gather = "%paged_attn_decode.3 = bf16[4,32]{1,0} fusion("
+    tr = tl.Trace([[(kernel, 10, 20), (other, 20, 35), (gather, 35, 40),
+                    (kernel, 50, 60), (kernel, 70, 80)]], [[]], [], [])
+    spans = [(0, 45), (50, 65)]
+    assert tl.ops_inside(tr, 0, spans, "custom-call",
+                         prefix="paged_attn_decode") == [(10, 20), (50, 60)]
+    assert tl.ops_inside(tr, 0, spans, "custom-call") == \
+        [(10, 20), (20, 35), (50, 60)]
+
+
 def test_idle_gaps_labelled_by_innermost_span():
     gaps = tl.idle_gaps(_trace(), 0, 0, 100)
     assert gaps[0] == ["bench.wait_arrival", 30e-9]

@@ -3,10 +3,11 @@ and depth so a whole run takes seconds on the CPU, driven through
 `harness.run` past the look for a chip."""
 from __future__ import annotations
 
-import copy
 import json
 import pathlib
 import time
+
+from bench import arch
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 
@@ -21,10 +22,7 @@ def bench() -> dict:
 def config(name: str) -> dict:
     with open(ROOT / "bench" / "configs" / f"{name}.json") as f:
         c = json.load(f)
-    c = copy.deepcopy(c)
-    c.update(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
-             num_attention_heads=4, head_dim=16, vocab_size=256,
-             num_key_value_heads=min(c["num_key_value_heads"], 4) // 2 or 1)
+    c = arch.load(c).tiny(c)
     c["lora"]["max_rank"] = 8
     c["server"].update(max_batch=4, cache_slots=96, page_size=16)
     return c

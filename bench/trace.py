@@ -148,15 +148,16 @@ def op_label(name: str) -> str:
 
 
 def ops_inside(trace: Trace, chip: int, spans: List[Interval],
-               op: str) -> List[Interval]:
-    """Intervals of the chip's ops with HLO opcode `op` that lie inside
-    one of `spans` (sorted, disjoint)."""
+               op: str, prefix: str = "") -> List[Interval]:
+    """Intervals of the chip's ops with HLO opcode `op`, whose instruction
+    name starts with `prefix`, that lie inside one of `spans` (sorted,
+    disjoint)."""
     out, j = [], 0
     for name, s, e in sorted(trace.ops[chip], key=lambda o: o[1]):
         while j < len(spans) and spans[j][1] < s:
             j += 1
         if j < len(spans) and spans[j][0] <= s and e <= spans[j][1] \
-                and opcode(name) == op:
+                and opcode(name) == op and name.lstrip("%").startswith(prefix):
             out.append((s, e))
     return out
 
